@@ -808,6 +808,7 @@ def bench_scaling():
 
 def main(argv=None) -> None:
     from repro import backends
+    from repro.launch.compile_cache import enable_compile_cache
 
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--json", metavar="PATH", default=None,
@@ -825,6 +826,7 @@ def main(argv=None) -> None:
                          "wall-clock spans plus cycle-domain schedule-IR "
                          "and mesh shard timelines")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     global SELECTED
     SELECTED = set(args.backend) if args.backend else None
     if args.trace:

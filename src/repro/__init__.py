@@ -1,36 +1,1 @@
-"""Photonic-SRAM in-memory-computing reproduction (jax).
-
-Importing ``repro`` installs a small forward-compat shim when running on an
-older jax: ``jax.sharding.AxisType`` and the ``axis_types=`` kwarg of
-``jax.make_mesh`` (used by ``launch.mesh`` and the dry-run) appeared after
-0.4.x; on such versions we provide the enum and accept-and-drop the kwarg —
-the Auto axis type is the implicit behavior there anyway.
-"""
-import enum
-import inspect
-
-import jax
-
-
-def _install_jax_compat():
-    if not hasattr(jax.sharding, "AxisType"):
-        class AxisType(enum.Enum):
-            Auto = "auto"
-            Explicit = "explicit"
-            Manual = "manual"
-
-        jax.sharding.AxisType = AxisType
-
-    if not hasattr(jax, "make_mesh"):
-        return  # pre-make_mesh jax: nothing to wrap
-
-    if "axis_types" not in inspect.signature(jax.make_mesh).parameters:
-        _orig_make_mesh = jax.make_mesh
-
-        def make_mesh(axis_shapes, axis_names, *, devices=None, axis_types=None):
-            return _orig_make_mesh(axis_shapes, axis_names, devices=devices)
-
-        jax.make_mesh = make_mesh
-
-
-_install_jax_compat()
+"""Photonic-SRAM in-memory-computing reproduction (jax)."""

@@ -28,6 +28,7 @@ from .lowering import (
     RESOLVED_LOWERINGS,
     resolve_exec_lowering,
     resolve_lowering,
+    resolve_stream_lowering,
 )
 from .workload import (
     MatmulWorkload,
@@ -55,4 +56,5 @@ __all__ = [
     "resolve_config",
     "resolve_exec_lowering",
     "resolve_lowering",
+    "resolve_stream_lowering",
 ]

@@ -267,7 +267,10 @@ class PallasBackend(Backend):
     deterministic heuristic is used, so untuned runs never regress.
 
     Lowering (env/platform probe included) resolves ONCE at construction;
-    every call dispatches on the stored resolved string. The fused paths
+    every call dispatches on the stored resolved strings, one per op
+    (:attr:`lowerings`). The fused streaming op resolves ``"auto"`` to its
+    ``"xla"`` lowering on TPU too (``lowering.resolve_stream_lowering``
+    says why). The fused paths
     reassociate float adds vs their oracles (``bit_exact=False``) and stay
     within the documented ADC envelope (``rel_tol=0.05``) vs ``exact``.
     """
@@ -275,12 +278,24 @@ class PallasBackend(Backend):
     def __init__(self, config=None, lowering: str = "auto",
                  compiled: bool = True, autotune: bool = False):
         super().__init__(config)
-        from .lowering import resolve_exec_lowering, resolve_lowering
+        from .lowering import (
+            resolve_exec_lowering, resolve_lowering, resolve_stream_lowering)
 
         self.compiled = bool(compiled)
         self.autotune = bool(autotune)
         self.lowering = (resolve_exec_lowering(lowering) if self.compiled
                          else resolve_lowering(lowering))
+        self.stream_lowering = resolve_stream_lowering(lowering)
+
+    @property
+    def lowerings(self) -> dict:
+        """The resolved lowering of each kernel op this backend reaches."""
+        if self.compiled:
+            return {"psram_matmul": self.lowering,
+                    "mttkrp_psram": self.lowering,
+                    "stream_mttkrp": self.stream_lowering}
+        return {"psram_matmul": self.lowering, "mttkrp": self.lowering,
+                "blocked_segment_sum": self.lowering}
 
     def capabilities(self) -> Capabilities:
         return Capabilities(
@@ -316,7 +331,7 @@ class PallasBackend(Backend):
 
             return fused_stream_mttkrp_op(
                 csf, tuple(factors), self.config,
-                adc_bits=self.config.adc.bits, backend=self.lowering,
+                adc_bits=self.config.adc.bits, backend=self.stream_lowering,
                 autotune=self.autotune)
         from repro.sparse.stream import stream_mttkrp_blocked
 

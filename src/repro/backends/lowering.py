@@ -17,6 +17,8 @@ probe result is cached; backends resolve at *construction*, not per call):
   interpret elsewhere. What the per-op kernel wrappers default to.
 * :func:`resolve_exec_lowering` — the execution contract of the fused
   (``compiled=True``) paths: Pallas on TPU, XLA elsewhere.
+* :func:`resolve_stream_lowering` — the fused streaming MTTKRP alone: XLA
+  on every platform, TPU included (see its docstring for why).
 
 ``REPRO_KERNEL_LOWERING`` overrides what ``"auto"`` resolves to in both
 (e.g. ``=interpret`` to force kernel-body validation everywhere). Before
@@ -80,4 +82,28 @@ def resolve_exec_lowering(backend: str = "auto") -> str:
     _validate(backend)
     if backend == "auto":
         return _env_override() or ("pallas" if on_tpu() else "xla")
+    return backend
+
+
+def resolve_stream_lowering(backend: str = "auto") -> str:
+    """Resolve for the fused streaming sparse MTTKRP
+    (``kernels.stream_mttkrp``): ``"auto"`` picks the ``"xla"`` lowering on
+    every platform, TPU included.
+
+    The Pallas lowering of that kernel does not compile for TPU v5e
+    (jax 0.9.0 / libtpu 0.0.34). Its per-chunk segment-pointer block
+    ``(1, E*n_seg)`` of the ``(nb, E*n_seg)`` array breaks the tiling rule
+    ("the last two dimensions of your block shape are divisible by 8 and
+    128 respectively, or be equal to the respective dimensions of the
+    overall array"). With that block made whole, Mosaic refuses the
+    data-dependent factor-row gather ``qs[d][idx]`` inside the kernel body
+    ("Shape mismatch in input, indices and output"), and the scatter
+    ``out_ref[...].at[sp].add`` is of the same kind. The ``"xla"`` lowering
+    runs the same ``_chunk_partials`` body as a ``lax.scan`` and compiles
+    for the chip. An explicit ``"pallas"`` request is passed through and
+    fails loudly at compile time; nothing falls back at run time.
+    """
+    _validate(backend)
+    if backend == "auto":
+        return _env_override() or "xla"
     return backend

@@ -8,7 +8,8 @@ matmul against the Khatri-Rao product,
 
 but materializing (B ⊙ C) in HBM costs J*K*R bytes — more than the tensor
 itself when R > 1. The kernel instead forms each (bk x R) KR tile *in VMEM*
-from a (1, R) row of B and a (bk, R) tile of C (CP 1, on the VPU), feeds the
+from row j of B (held whole in VMEM) and a (bk, R) tile of C (CP 1, on
+the VPU), feeds the
 MXU with X tiles (CP 2's scaling is the matmul itself), and accumulates into
 the output across the grid (CP 3). HBM traffic: X once + tiny factor reads.
 
@@ -35,8 +36,9 @@ def _kernel(x_ref, b_ref, c_ref, out_ref, acc_ref, *, nj: int, nk: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # CP 1 in VMEM: one row of B broadcast against a tile of C
-    kr = b_ref[...] * c_ref[...]          # (bk, R) on the VPU
+    # CP 1 in VMEM: one row of the VMEM-resident B broadcast against a
+    # tile of C
+    kr = b_ref[pl.ds(j, 1), :] * c_ref[...]   # (bk, R) on the VPU
     x = x_ref[...]                        # (bi, bk) slice of X_(0) at (i, j*K+k)
     # CP 2 + CP 3 on the MXU: scale-by-tensor-element and accumulate
     acc_ref[...] += jax.lax.dot_general(
@@ -74,7 +76,9 @@ def mttkrp_fused(
             # X_(0) tile at row-block ii, columns [j*K + kk*bk : ... + bk].
             # Block shape (bi, bk) with index (ii, j*nk + kk) walks row-major.
             pl.BlockSpec((bi, bk), lambda ii, j_, kk: (ii, j_ * nk + kk)),
-            pl.BlockSpec((1, r), lambda ii, j_, kk: (j_, 0)),
+            # the small (J, R) factor stays whole in VMEM (a (1, R) block is
+            # not tile-aligned); the kernel slices row j
+            pl.BlockSpec((j, r), lambda ii, j_, kk: (0, 0)),
             pl.BlockSpec((bk, r), lambda ii, j_, kk: (kk, 0)),
         ],
         out_specs=pl.BlockSpec((bi, r), lambda ii, j_, kk: (ii, 0)),
@@ -98,8 +102,9 @@ def _psram_kernel(qx_ref, sx_ref, qb_ref, sb_ref, qc_ref, sc_ref, out_ref,
 
     # CP 1 in VMEM from the *quantized* factors: the int8xint8 row products
     # are exact in f32 (<= 127^2), the per-row scales fold into one multiply
-    kr = (qb_ref[...].astype(jnp.float32) * qc_ref[...].astype(jnp.float32)
-          ) * (sb_ref[...] * sc_ref[...])                  # (bk, R)
+    qb = qb_ref[pl.ds(j, 1), :]                             # (1, R) f32
+    sb = sb_ref[pl.ds(j, 1), :]                             # (1, 1)
+    kr = (qb * qc_ref[...].astype(jnp.float32)) * (sb * sc_ref[...])  # (bk, R)
     x = qx_ref[...].astype(jnp.float32) * sx_ref[...]      # (bi, bk)
     acc_ref[...] += jax.lax.dot_general(
         x, kr, (((1,), (0,)), ((), ())),
@@ -142,14 +147,17 @@ def mttkrp_psram_fused(
     assert i % bi == 0 and k % bk == 0
     nj, nk = j, k // bk
     grid = (i // bi, nj, nk)
+    # qb rides as f32 (its int8 codes, exactly): Mosaic slices row j of a
+    # 32-bit VMEM array at a dynamic sublane offset, not of a packed int8 one
     return pl.pallas_call(
         functools.partial(_psram_kernel, nj=nj, nk=nk, adc_bits=adc_bits),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bi, bk), lambda ii, j_, kk: (ii, j_ * nk + kk)),
             pl.BlockSpec((bi, 1), lambda ii, j_, kk: (ii, 0)),
-            pl.BlockSpec((1, r), lambda ii, j_, kk: (j_, 0)),
-            pl.BlockSpec((1, 1), lambda ii, j_, kk: (j_, 0)),
+            # (J, R) and (J, 1) whole in VMEM, row j sliced in the kernel
+            pl.BlockSpec((j, r), lambda ii, j_, kk: (0, 0)),
+            pl.BlockSpec((j, 1), lambda ii, j_, kk: (0, 0)),
             pl.BlockSpec((bk, r), lambda ii, j_, kk: (kk, 0)),
             pl.BlockSpec((bk, 1), lambda ii, j_, kk: (kk, 0)),
         ],
@@ -157,7 +165,7 @@ def mttkrp_psram_fused(
         out_shape=jax.ShapeDtypeStruct((i, r), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bi, r), jnp.float32)],
         interpret=interpret,
-    )(qx0, sx, qb, sb, qc, sc)
+    )(qx0, sx, qb.astype(jnp.float32), sb, qc, sc)
 
 
 @functools.partial(jax.jit, static_argnames=("bi", "adc_bits"))

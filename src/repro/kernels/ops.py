@@ -201,12 +201,12 @@ def fused_stream_mttkrp_op(
     kernel); see kernels/stream_mttkrp.py. ``autotune=True`` sweeps and
     caches the chunk size for this workload's tune key."""
     from repro.backends.base import resolve_config
-    from repro.backends.lowering import resolve_exec_lowering
+    from repro.backends.lowering import resolve_stream_lowering
     from .autotune import stream_params
     from .stream_mttkrp import fused_stream_mttkrp
 
     cfg = resolve_config(config)
-    low = resolve_exec_lowering(backend)
+    low = resolve_stream_lowering(backend)
     if exec_blocks is None:
         exec_blocks = stream_params(
             csf, tuple(factors), cfg, tune=autotune, adc_bits=adc_bits,

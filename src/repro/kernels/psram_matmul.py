@@ -30,10 +30,11 @@ def _kernel(qx_ref, qw_ref, sx_ref, sw_ref, out_ref, acc_ref, *, nk: int, adc_bi
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = qx_ref[...].astype(jnp.int32)
-    b = qw_ref[...].astype(jnp.int32)
+    # int8 operands straight into the MXU, int32 accumulation (widening
+    # them first is refused by Mosaic: the MXU has no int32 x int32 mode)
     acc_ref[...] += jax.lax.dot_general(
-        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32
+        qx_ref[...], qw_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32,
     )
 
     @pl.when(kk == nk - 1)

@@ -27,7 +27,7 @@ from jax.experimental import pallas as pl
 
 
 def _kernel(data_ref, seg_ref, out_ref, *, n_seg: int):
-    seg = seg_ref[...]                           # (1, bn) int32 local ids
+    seg = seg_ref[0]                             # (1, bn) int32 local ids
     # gather masks: one row per segment, formed in VMEM (2-D iota for TPU)
     sids = jax.lax.broadcasted_iota(jnp.int32, (n_seg, seg.shape[1]), 0)
     mask = (sids == seg).astype(jnp.float32)     # (S, bn) one-hot
@@ -55,14 +55,18 @@ def blocked_segment_sum(
     """
     b, bn, r = data.shape
     assert seg_ids.shape == (b, bn), (seg_ids.shape, data.shape)
+    # a leading unit axis makes the id block's last two dims (1, bn) whole,
+    # which the TPU tiling rule accepts for any B (a (1, bn) block of a
+    # (B, bn) array is not 8-row aligned)
+    seg3 = seg_ids.reshape(b, 1, bn)
     return pl.pallas_call(
         functools.partial(_kernel, n_seg=n_seg),
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, bn, r), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, bn), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, bn), lambda i: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, n_seg, r), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, n_seg, r), jnp.float32),
         interpret=interpret,
-    )(data, seg_ids)
+    )(data, seg3)

@@ -33,12 +33,13 @@ The four lowerings of this one body (``backends.lowering.EXEC_LOWERINGS``):
 * ``"pallas"``    — real ``pallas_call``, grid over chunks, factors resident
   in VMEM, chunk operands double-buffered by the Pallas pipeline (each
   grid step's block specs prefetch the next chunk while the current one
-  drains), the output accumulator ref carrying across the grid. TPU only.
+  drains), the output accumulator ref carrying across the grid. The TPU
+  v5e compiler refuses it (the in-kernel row gather and scatter), so
+  ``"auto"`` never picks it: ``backends.lowering.resolve_stream_lowering``.
 * ``"interpret"`` — the same ``pallas_call``, Python-executed. CPU
   validation of the kernel body; far too slow to race.
 * ``"xla"``       — the same body as a ``lax.scan`` step over chunks, jitted
-  whole. The fast CPU lowering (the committed BENCH rows): XLA pipelines
-  the gathers exactly like the Pallas double-buffer would.
+  whole: what ``"auto"`` runs on every platform, the TPU included.
 * ``"ref"``       — the flat oracle: every chunk at once, one scatter; no
   scan, no carry threading. Parity anchor for the other three.
 
@@ -279,7 +280,7 @@ def fused_stream_mttkrp(csf, factors, config=None, adc_bits: int = 16,
     with the scan executors), quantizes the non-target factors per row, and
     drains the stream through the requested lowering of the fused body.
     ``lowering`` must already be resolved (``backends.lowering.
-    resolve_exec_lowering``); ``exec_blocks=None`` asks ``kernels.autotune``
+    resolve_stream_lowering``); ``exec_blocks=None`` asks ``kernels.autotune``
     for the cached winner or its deterministic heuristic.
     """
     from repro.backends.base import resolve_config

@@ -1,9 +1,10 @@
 """Production meshes.
 
 Defined as FUNCTIONS so importing this module never touches jax device
-state. The dry-run sets XLA_FLAGS=--xla_force_host_platform_device_count=512
-before any jax import; real launches get the same shapes from the TPU
-runtime topology.
+state. Real launches take their devices from the TPU runtime; the pod-scale
+dry-run alone emulates 512 CPU devices (it sets
+XLA_FLAGS=--xla_force_host_platform_device_count=512 before any jax
+import).
 """
 from __future__ import annotations
 
@@ -34,9 +35,10 @@ def make_array_mesh(n_arrays: int | None = None) -> jax.sharding.Mesh:
     One device hosts one array's shard of the nonzero stream
     (``repro.sparse.mesh``); the ``"array"`` axis is a data axis under the
     dist.sharding rules, so ``sparse.arrays_for_mesh`` sees it like any
-    batch claim. ``n_arrays`` defaults to every local device; validate on
-    CPU with ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` set
-    before the first jax import.
+    batch claim. ``n_arrays`` defaults to every local device: the chips of
+    a TPU host (four on a v5e 2x2 host). To rehearse a mesh on a machine
+    without them, set ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
+    before the first jax import; the CPU then shows N virtual devices.
     """
     import numpy as np
 
@@ -46,9 +48,11 @@ def make_array_mesh(n_arrays: int | None = None) -> jax.sharding.Mesh:
         raise ValueError("need at least one array")
     if n > len(devs):
         raise ValueError(
-            f"asked for {n} arrays but only {len(devs)} devices exist; "
-            "set XLA_FLAGS=--xla_force_host_platform_device_count=N before "
-            "importing jax to emulate more on CPU"
+            f"asked for {n} arrays but only {len(devs)} "
+            f"{devs[0].platform} devices exist; run on a host with at least "
+            f"{n} chips, or, to rehearse on a CPU, set "
+            "XLA_FLAGS=--xla_force_host_platform_device_count=N before "
+            "importing jax"
         )
     return jax.sharding.Mesh(np.asarray(devs[:n]), ("array",))
 

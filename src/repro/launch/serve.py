@@ -20,8 +20,11 @@ def main():
     import jax
     import jax.numpy as jnp
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.registry import get_config, get_module
     from repro.serve import ServeEngine
+
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

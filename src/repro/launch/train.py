@@ -39,6 +39,9 @@ def main():
                     help="multi-host: call jax.distributed.initialize()")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.distributed:
         import jax
         jax.distributed.initialize()

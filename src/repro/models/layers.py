@@ -72,6 +72,24 @@ def shapes_of(defs, dtype):
     )
 
 
+@partial(jax.jit, static_argnames=("shape", "scale", "dtype"))
+def _normal_leaf(key, shape, scale, dtype):
+    # one jitted draw per leaf: the f32 normals fuse into the cast, so a
+    # bf16 leaf never has a full-size f32 twin on the device (eagerly, a
+    # layer-stacked MLP leaf of an 8B model would double the peak)
+    return (jax.random.normal(key, shape) * scale).astype(dtype)
+
+
+@partial(jax.jit, static_argnames=("shape",))
+def _qnormal_leaf(key, shape):
+    from repro.core.quantization import quantize_symmetric
+
+    fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
+    w = jax.random.normal(key, shape) / math.sqrt(fan_in)
+    q, _ = quantize_symmetric(w, axis=tuple(range(len(shape) - 1)))
+    return q
+
+
 def init_params(key, defs, dtype=jnp.float32):
     leaves, treedef = jax.tree.flatten(defs, is_leaf=_is_def)
     keys = jax.random.split(key, len(leaves))
@@ -83,18 +101,14 @@ def init_params(key, defs, dtype=jnp.float32):
         if d["init"] == "ones":
             return jnp.ones(d["shape"], dt)
         if d["init"] == "qnormal":  # pre-programmed array words
-            fan_in = d["shape"][-2] if len(d["shape"]) >= 2 else d["shape"][-1]
-            w = jax.random.normal(k, d["shape"]) / math.sqrt(fan_in)
-            from repro.core.quantization import quantize_symmetric
-            q, _ = quantize_symmetric(w, axis=tuple(range(len(d["shape"]) - 1)))
-            return q
+            return _qnormal_leaf(k, d["shape"])
         if d["init"] == "qscale":
             # matches qnormal: scale ~= max|w| / 127 per output column
             fan_in = d["shape"][-1]
             return jnp.full(d["shape"], 4.0 / math.sqrt(max(fan_in, 2)) / 127.0, dt)
         fan_in = d["shape"][-2] if len(d["shape"]) >= 2 else d["shape"][-1]
         scale = d["scale"] if d["scale"] is not None else 1.0 / math.sqrt(fan_in)
-        return (jax.random.normal(k, d["shape"]) * scale).astype(dt)
+        return _normal_leaf(k, d["shape"], float(scale), dt)
 
     return jax.tree.unflatten(treedef, [one(k, d) for k, d in zip(keys, leaves)])
 

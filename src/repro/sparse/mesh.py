@@ -38,8 +38,7 @@ from functools import lru_cache, partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import obs
 from repro.backends.base import resolve_config
@@ -170,17 +169,24 @@ def _faulty_values(vp):
 
 
 def _mesh_layout(csf: CSF, meshed: MeshedSparseTensor, lowering: str,
-                 rows: int, exec_blocks: int):
-    """Per-(CSF, partition, lowering) stacked operands, cached on the CSF."""
+                 rows: int, exec_blocks: int, mesh: Mesh):
+    """Per-(CSF, partition, lowering, mesh) stacked operands, cached on the
+    CSF and placed once: slice ``a`` of each stack's leading array axis
+    lives on the mesh's device ``a``."""
     out_rows = csf.shape[csf.mode_order[0]]
     key = ("_mesh_layout", lowering, len(meshed.shards), rows, exec_blocks,
-           meshed.partitions)
+           meshed.partitions, mesh)
     cached = csf.__dict__.get(key)
     if cached is None:
         if lowering == "eager":
-            cached = _eager_shard_stack(meshed, out_rows, rows * exec_blocks)
+            stacks = _eager_shard_stack(meshed, out_rows, rows * exec_blocks)
         else:
-            cached = _blocked_shard_stack(meshed, out_rows, rows, exec_blocks)
+            *stacks, n_seg = _blocked_shard_stack(meshed, out_rows, rows,
+                                                  exec_blocks)
+        by_array = NamedSharding(mesh, P(mesh.axis_names[0]))
+        cached = tuple(jax.device_put(a, by_array) for a in stacks)
+        if lowering != "eager":
+            cached += (n_seg,)
         csf.__dict__[key] = cached
     return cached
 
@@ -258,8 +264,8 @@ def _mesh_executor(mesh: Mesh, lowering: str, mode: int, out_rows: int,
             f"unknown mesh lowering {lowering!r}; pick one of {MESH_LOWERINGS}"
         )
 
-    return jax.jit(shard_map(device_fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=P(), check_rep=False))
+    return jax.jit(jax.shard_map(device_fn, mesh=mesh, in_specs=in_specs,
+                                 out_specs=P(), check_vma=False))
 
 
 def mesh_stream_mttkrp(
@@ -294,24 +300,30 @@ def mesh_stream_mttkrp(
     rows = cfg.rows
     max_nnz = max(1, max(s.nnz for s in meshed.shards))
     eb = _exec_blocks(rows, max(1, -(-max_nnz // rows)), exec_blocks)
+    layout = _mesh_layout(csf, meshed, lowering, rows, eb, mesh)
     # spans cannot cross into the jitted shard_map body; the per-shard view
     # is host-side — one span per planned shard with its nnz (the imbalance
-    # the planner fought) plus the execute span around the SPMD dispatch.
-    # The cycle-domain per-array tracks come from obs.mesh_timeline.
+    # the planner fought) and the device its stack slice lives on, plus the
+    # execute span around the SPMD dispatch. The cycle-domain per-array
+    # tracks come from obs.mesh_timeline.
     if obs.enabled():
+        devs = {sh.index[0].start or 0: sh.device
+                for sh in layout[0].addressable_shards}
         for i, s in enumerate(meshed.shards):
-            with obs.span(f"mesh/shard{i}/plan", nnz=s.nnz):
+            d = devs[i]
+            with obs.span(f"mesh/shard{i}/plan", nnz=s.nnz,
+                          device=f"{d.platform}:{d.id}"):
                 pass
             obs.counter(f"mesh/shard{i}/nnz", s.nnz)
     with obs.span("mesh/stream/execute", nnz=csf.nnz, n_arrays=n,
                   lowering=lowering, planner=planner, mode=mode):
         if lowering == "eager":
-            ip, rp, vp = _mesh_layout(csf, meshed, lowering, rows, eb)
+            ip, rp, vp = layout
             vp = _faulty_values(vp)
             fn = _mesh_executor(mesh, lowering, mode, out_rows, 0, psram,
                                 adc_bits)
             return fn(ip, rp, vp, tuple(factors))
-        ip, vp, lp, sp, n_seg = _mesh_layout(csf, meshed, lowering, rows, eb)
+        ip, vp, lp, sp, n_seg = layout
         vp = _faulty_values(vp)
         if lowering == "fused":
             from repro.kernels.stream_mttkrp import stream_factor_quants
@@ -340,8 +352,8 @@ def _gram_executor(mesh: Mesh):
                                 preferred_element_type=jnp.float32),
             axis)
 
-    return jax.jit(shard_map(device_fn, mesh=mesh, in_specs=(P(axis),),
-                             out_specs=P(), check_rep=False))
+    return jax.jit(jax.shard_map(device_fn, mesh=mesh, in_specs=(P(axis),),
+                                 out_specs=P(), check_vma=False))
 
 
 def mesh_gram(f: jax.Array, mesh: Mesh | None = None,

@@ -413,6 +413,32 @@ def test_exec_lowering_registry_owned():
         backends.resolve_exec_lowering("cuda")
 
 
+def test_stream_lowering_on_tpu_is_xla(monkeypatch):
+    """On a TPU ``"auto"`` sends the fused stream op to its ``"xla"``
+    lowering (the v5e compiler refuses its Pallas kernel) while the other
+    ops take real Pallas; an explicit ``"pallas"`` request is passed through
+    untouched, so it fails loudly at compile time instead of falling back."""
+    from repro.backends import lowering
+
+    monkeypatch.delenv("REPRO_KERNEL_LOWERING", raising=False)
+    lowering._env_override.cache_clear()
+    monkeypatch.setattr(lowering, "on_tpu", lambda: True)
+    assert backends.resolve_stream_lowering("auto") == "xla"
+    assert backends.resolve_stream_lowering("pallas") == "pallas"
+    assert backends.resolve_exec_lowering("auto") == "pallas"
+    assert backends.resolve_lowering("auto") == "pallas"
+    with pytest.raises(ValueError, match="unknown kernel lowering"):
+        backends.resolve_stream_lowering("cuda")
+    assert backends.get("pallas").lowerings == {
+        "psram_matmul": "pallas", "mttkrp_psram": "pallas",
+        "stream_mttkrp": "xla"}
+    assert backends.get("pallas", lowering="pallas").lowerings[
+        "stream_mttkrp"] == "pallas"
+    monkeypatch.setattr(lowering, "on_tpu", lambda: False)
+    assert backends.get("pallas").lowerings == {
+        "psram_matmul": "xla", "mttkrp_psram": "xla", "stream_mttkrp": "xla"}
+
+
 def test_pallas_capabilities_compiled_autotune_wiring():
     caps = backends.get("pallas").capabilities()
     assert caps.compiled and not caps.autotune      # fused family by default

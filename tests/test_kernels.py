@@ -212,6 +212,67 @@ def test_mttkrp_psram_within_quantization_envelope(key):
     assert rel < 0.05
 
 
+# ------------- kernels with TPU-aligned blocks, at aligned shapes -------------
+
+@pytest.mark.parametrize("i,j,k,r", [(256, 8, 256, 32), (128, 16, 512, 128)])
+def test_mttkrp_fused_aligned_vs_ref(key, i, j, k, r):
+    """Exact dense kernel with B held whole in VMEM (row j sliced in the
+    kernel) at MXU-aligned tiles, against the oracle."""
+    x0 = jax.random.normal(key, (i, j * k))
+    b = jax.random.normal(jax.random.PRNGKey(1), (j, r))
+    c = jax.random.normal(jax.random.PRNGKey(2), (k, r))
+    got = mttkrp_fused(x0, b, c, bi=128, bk=128, interpret=True)
+    want = ref.mttkrp_ref(x0, b, c)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("i,j,k,r", [(256, 8, 256, 32), (128, 16, 512, 128)])
+def test_mttkrp_psram_aligned_vs_ref(key, i, j, k, r):
+    """Quantized dense kernel with qb/sb held whole in VMEM, at aligned
+    tiles, against the oracle: within one ADC code (the tile walk
+    reassociates the f32 accumulation)."""
+    x0 = jax.random.normal(key, (i, j * k))
+    b = jax.random.normal(jax.random.PRNGKey(1), (j, r))
+    c = jax.random.normal(jax.random.PRNGKey(2), (k, r))
+    ops = quantize_mttkrp_operands(x0, b, c)
+    got = mttkrp_psram_fused(*ops, bi=128, bk=128, interpret=True)
+    want = ref.mttkrp_psram_ref(*ops, bi=128)
+    step = 2.0 * float(jnp.max(jnp.abs(want))) / 2 ** 16
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2 * step)
+
+
+@pytest.mark.parametrize("b,bn,r,n_seg", [(16, 256, 32, 64), (9, 128, 128, 128)])
+def test_blocked_segment_sum_aligned_vs_ref(b, bn, r, n_seg):
+    """Blocked segment sum with the (B, 1, bn) id layout, against the
+    one-hot oracle."""
+    from repro.kernels.segment_sum import blocked_segment_sum
+
+    data = jax.random.normal(jax.random.PRNGKey(4), (b, bn, r))
+    seg = jnp.sort(jax.random.randint(jax.random.PRNGKey(5), (b, bn), 0,
+                                      n_seg), axis=1).astype(jnp.int32)
+    got = blocked_segment_sum(data, seg, n_seg, interpret=True)
+    want = ref.blocked_segment_sum_ref(data, seg, n_seg)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("m,k,n", [(256, 1024, 256), (128, 4096, 512)])
+def test_psram_matmul_int8_operands_aligned_vs_ref(key, m, k, n):
+    """int8 operands fed straight to the MXU dot, int32 accumulation: bit
+    for bit the oracle's int32 matmul + ADC, at the default 128/128/512
+    tiles."""
+    x = jax.random.normal(key, (m, k))
+    w = jax.random.normal(jax.random.PRNGKey(1), (k, n))
+    qx, sx = quantize_symmetric(x, axis=-1)
+    qw, sw = quantize_symmetric(w, axis=0)
+    sx, sw = sx.reshape(m, 1), sw.reshape(1, n)
+    got = psram_matmul(qx, qw, sx, sw, interpret=True)
+    want = ref.psram_matmul_ref(qx, qw, sx, sw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 # ---------------- fused streaming MTTKRP ----------------
 
 def _small_stream_case(nnz=800, shape=(30, 24, 18), rank=6):

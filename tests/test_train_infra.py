@@ -190,3 +190,28 @@ def test_factored_optimizer_memory_and_convergence():
     small = state_structs(jax.tree.map(
         lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype), params), oc)
     assert nbytes(small) < 0.7 * nbytes(full)
+
+
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/jax-cache"])
+def test_compile_cache_placement(monkeypatch, env_dir):
+    """The entry points' cache: ``JAX_COMPILATION_CACHE_DIR`` wins and is
+    left to JAX; unset, the cache sits at the fixed ``<repo>/.jax_cache``."""
+    from repro.launch import compile_cache
+
+    if env_dir is None:
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    else:
+        monkeypatch.setenv(compile_cache.ENV_VAR, env_dir)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        got = compile_cache.enable_compile_cache()
+        if env_dir is None:
+            want = os.path.join(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__))), ".jax_cache")
+            assert got == want
+            assert jax.config.jax_compilation_cache_dir == want
+        else:
+            assert got == env_dir
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

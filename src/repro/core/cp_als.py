@@ -194,93 +194,102 @@ def cp_als(
             "compiled= selects a backend's fast mode and needs backend=; "
             "the default exact paths have no compiled variant"
         )
-    callable_fn = be = None
-    lossy = None
-    if backend is not None:
-        callable_fn, be = _resolve_backend(backend, config, compiled)
-        lossy = True if callable_fn is not None else be.capabilities().lossy
-    # a backend that sorts into a mode-rooted CSF per call (psram-stream,
-    # pallas sparse) must see prebuilt per-mode CSFs, or every sweep re-sorts
-    # the nonzeros — mirror the sparse branch's lazy cache for coo/dense too
-    wants_csf = be is not None and be.capabilities().prefers_csf
-    exact_last_mode_fn = None
-    if sparse is not None:
-        if coo is not None or x is not None:
-            raise ValueError("pass exactly one of x / coo / sparse")
-        from repro.sparse.formats import CSF, SortedCOO, csf_for_mode
-        from repro.sparse.stream import stream_mttkrp
+    # from entry to the first sweep: the backend, the dedupe sort and norm,
+    # the initial factors and their Grams
+    with obs.span("als/prepare", rank=rank):
+        callable_fn = be = None
+        lossy = None
+        if backend is not None:
+            callable_fn, be = _resolve_backend(backend, config, compiled)
+            lossy = (True if callable_fn is not None
+                     else be.capabilities().lossy)
+        # a backend that sorts into a mode-rooted CSF per call (psram-stream,
+        # pallas sparse) must see prebuilt per-mode CSFs, or every sweep
+        # re-sorts the nonzeros — mirror the sparse branch's lazy cache for
+        # coo/dense too
+        wants_csf = be is not None and be.capabilities().prefers_csf
+        exact_last_mode_fn = None
+        if sparse is not None:
+            if coo is not None or x is not None:
+                raise ValueError("pass exactly one of x / coo / sparse")
+            from repro.sparse.formats import CSF, SortedCOO, csf_for_mode
+            from repro.sparse.stream import stream_mttkrp
 
-        base = sparse.to_coo() if isinstance(sparse, CSF) else sparse
-        # duplicate coordinates are legal in the containers but would corrupt
-        # ||X|| (norm of values ≠ norm of the collapsed tensor) and with it
-        # the fit and the tol stopping rule — merge them up front
-        base = SortedCOO.from_coo(base, getattr(base, "mode_order", None),
-                                  dedupe=True)
-        shape = tuple(base.shape)
-        norm_x = jnp.linalg.norm(base.values)
-        # per-mode CSFs are the expensive host-side preprocessing: callers
-        # that already built them pass csfs= through, and a callable backend
-        # only ever needs the last mode (exact_fit), so build lazily on
-        # first use and share the cache with the registry backend
-        built: dict = {}
+            base = sparse.to_coo() if isinstance(sparse, CSF) else sparse
+            # duplicate coordinates are legal in the containers but would
+            # corrupt ||X|| (norm of values ≠ norm of the collapsed tensor)
+            # and with it the fit and the tol stopping rule — merge them up
+            # front
+            base = SortedCOO.from_coo(base, getattr(base, "mode_order", None),
+                                      dedupe=True)
+            shape = tuple(base.shape)
+            norm_x = jnp.linalg.norm(base.values)
+            # per-mode CSFs are the expensive host-side preprocessing:
+            # callers that already built them pass csfs= through, and a
+            # callable backend only ever needs the last mode (exact_fit), so
+            # build lazily on first use and share the cache with the
+            # registry backend
+            built: dict = {}
 
-        def mode_csf(m):
-            if csfs is not None:
-                return csfs[m]
-            if m not in built:
-                built[m] = csf_for_mode(base, m)
-            return built[m]
+            def mode_csf(m):
+                if csfs is not None:
+                    return csfs[m]
+                if m not in built:
+                    built[m] = csf_for_mode(base, m)
+                return built[m]
 
-        default_fn = lambda _, fs, m: stream_mttkrp(mode_csf(m), tuple(fs))
-        exact_last_mode_fn = default_fn
-        backend_data = mode_csf          # a backend sees the per-mode CSF
-    elif coo is not None:
-        indices, values, shape = coo
-        norm_x = jnp.linalg.norm(values)
-        default_fn = lambda _, fs, m: mttkrp_sparse(
-            indices, values, tuple(fs), m, shape[m]
-        )
-        exact_last_mode_fn = default_fn
-        if wants_csf:
-            backend_data = _csf_cache(
-                lambda: (indices, values, tuple(shape)))
+            default_fn = lambda _, fs, m: stream_mttkrp(mode_csf(m),
+                                                        tuple(fs))
+            exact_last_mode_fn = default_fn
+            backend_data = mode_csf          # a backend sees the per-mode CSF
+        elif coo is not None:
+            indices, values, shape = coo
+            norm_x = jnp.linalg.norm(values)
+            default_fn = lambda _, fs, m: mttkrp_sparse(
+                indices, values, tuple(fs), m, shape[m]
+            )
+            exact_last_mode_fn = default_fn
+            if wants_csf:
+                backend_data = _csf_cache(
+                    lambda: (indices, values, tuple(shape)))
+            else:
+                backend_data = lambda m: (indices, values, tuple(shape))
         else:
-            backend_data = lambda m: (indices, values, tuple(shape))
-    else:
-        shape = x.shape
-        norm_x = jnp.linalg.norm(x)
-        default_fn = lambda t, fs, m: mttkrp_dense(t, fs, m)
-        exact_last_mode_fn = default_fn
-        if wants_csf:
-            from .mttkrp import dense_to_coo
+            shape = x.shape
+            norm_x = jnp.linalg.norm(x)
+            default_fn = lambda t, fs, m: mttkrp_dense(t, fs, m)
+            exact_last_mode_fn = default_fn
+            if wants_csf:
+                from .mttkrp import dense_to_coo
 
-            backend_data = _csf_cache(
-                lambda: (*dense_to_coo(x), tuple(x.shape)))
+                backend_data = _csf_cache(
+                    lambda: (*dense_to_coo(x), tuple(x.shape)))
+            else:
+                backend_data = lambda m: x
+        if callable_fn is not None:
+            fn = callable_fn  # legacy contract: fn(x_or_none, factors, mode)
+        elif be is not None:
+            fn = lambda _, fs, m: be.mttkrp(backend_data(m), tuple(fs), m)
         else:
-            backend_data = lambda m: x
-    if callable_fn is not None:
-        fn = callable_fn      # legacy contract: fn(x_or_none, factors, mode)
-    elif be is not None:
-        fn = lambda _, fs, m: be.mttkrp(backend_data(m), tuple(fs), m)
-    else:
-        fn = default_fn
-    if exact_fit is None:
-        # a lossy engine biases the inner-product fit; exact engines don't
-        exact_fit = bool(lossy)
+            fn = default_fn
+        if exact_fit is None:
+            # a lossy engine biases the inner-product fit; exact engines don't
+            exact_fit = bool(lossy)
 
-    factors = init_factors(key, tuple(shape), rank)
-    lam = jnp.ones((rank,))
-    prev_fit, fit = -1.0, 0.0
-    it = 0
-    last = len(shape) - 1
-    # per-sweep Gram reuse: each (R, R) Gram changes only when its factor
-    # does, so keep them current incrementally — N Gram matmuls per sweep
-    # instead of N·(N-1) + N (the bits are unchanged: same op, same operand).
-    # The Gram itself comes from the backend: local ``f.T @ f`` everywhere
-    # except distributed backends ("psram-mesh"), whose override all-reduces
-    # per-shard partial Grams — the sweep then executes SPMD end to end.
-    gram = be.gram if be is not None else (lambda f: f.T @ f)
-    grams = [gram(f) for f in factors]
+        factors = init_factors(key, tuple(shape), rank)
+        lam = jnp.ones((rank,))
+        prev_fit, fit = -1.0, 0.0
+        it = 0
+        last = len(shape) - 1
+        # per-sweep Gram reuse: each (R, R) Gram changes only when its
+        # factor does, so keep them current incrementally — N Gram matmuls
+        # per sweep instead of N·(N-1) + N (the bits are unchanged: same op,
+        # same operand). The Gram itself comes from the backend: local
+        # ``f.T @ f`` everywhere except distributed backends ("psram-mesh"),
+        # whose override all-reduces per-shard partial Grams — the sweep
+        # then executes SPMD end to end.
+        gram = be.gram if be is not None else (lambda f: f.T @ f)
+        grams = [gram(f) for f in factors]
     backend_name = be.name if be is not None else (
         "callable" if callable_fn is not None else "default")
     for it in range(1, n_iter + 1):
@@ -305,7 +314,8 @@ def cp_als(
             norm_hat_sq = jnp.sum(g_all)
             resid = jnp.sqrt(
                 jnp.maximum(norm_x**2 + norm_hat_sq - 2 * inner, 0.0))
-            fit = float(1.0 - resid / norm_x)
+            with obs.span("als/fit/read"):   # the host waits on the device
+                fit = float(1.0 - resid / norm_x)
         if abs(fit - prev_fit) < tol:
             break
         prev_fit = fit

@@ -30,7 +30,7 @@ levels, lowercase:
 
 * **layer** — the subsystem: ``backend``, ``schedule``, ``stream``,
   ``mesh``, ``als``, ``autotune``, ``train``, ``serve``, ``bench``,
-  ``obs``, ``fault``.
+  ``obs``, ``fault``, ``py`` (the Python runtime: ``py/gc``).
 * **component** — the object or phase within it: a backend name
   (``backend/psram-stream/...``), an executor (``schedule/execute``), a
   loop phase (``als/sweep``), a tuning key (``autotune/trial``).
@@ -45,15 +45,29 @@ span **args** (keyword arguments to ``span``/``stopwatch``), not in the
 name — names should aggregate across calls, args should vary.
 
 The live serving loop (:mod:`repro.serve.loop`) instruments every engine
-phase under the ``serve`` layer: spans ``serve/admit`` (args: queue
-depth), ``serve/prefill`` (rid, prompt length), ``serve/decode`` (batch,
-view length), ``serve/offload`` (batch — the scheduler's pricing
-decision), ``serve/evict`` (rid of the preempted row); counters
-``serve/admitted``, ``serve/rejected``, ``serve/preempted``,
-``serve/prefills``, ``serve/decode_steps``, ``serve/tokens``. A traced
-serve run therefore shows the admission queue, each batch's step, and
-every preemption as stacked slices on the wall-clock track, next to the
-virtual mesh timelines.
+phase under the ``serve`` layer:
+
+* ``serve/idle`` — one per idle stretch, from the first loop iteration that
+  finds nothing queued or active until work arrives;
+* ``serve/enqueue`` (rid, late_ms) — the producer releasing a request;
+  ``late_ms`` is the enqueue time minus the request's due time;
+* ``serve/admit`` (queued) — the admission pass, holding each
+  ``serve/prefill`` (rid, prompt);
+* ``serve/evict`` (rid) — a preempted row;
+* ``serve/step`` (batch) — one decode step, holding ``serve/offload``
+  (batch: the scheduler's pricing decision), ``serve/decode/build`` (batch,
+  view: the host index arrays), ``serve/decode`` (batch, view: dispatch and
+  the logits' host read; its duration feeds the offload scheduler) and
+  ``serve/sample`` (batch: numpy sampling and per-row bookkeeping);
+* counters ``serve/admitted``, ``serve/rejected``, ``serve/preempted``:
+  the admission layer's attempts and failures (tokens, prefills and steps
+  are in the ``ServeReport``).
+
+CP-ALS (:mod:`repro.core.cp_als`) records ``als/prepare`` (rank: entry to
+the first sweep — the backend, the dedupe sort and norm, the initial
+factors and Grams), then per sweep ``als/sweep`` (iteration, backend,
+rank) and ``als/fit`` (iteration, exact), whose ``als/fit/read`` is the
+host read of the fit, where the host waits on the device.
 
 The fault-tolerance stack (:mod:`repro.faults`) instruments under the
 ``fault`` layer, split by phase: spans ``fault/inject/armed`` (args: seed
@@ -70,11 +84,21 @@ degraded`` and ``fault/mesh/redrive`` (dead-array recovery), ``serve/fail``
 same zero-cost discipline as the null span: one module-global read when no
 plan is armed.
 
-The tracer is zero-cost when disabled: ``span()`` returns a shared no-op
-context manager without reading a clock (overhead asserted in
-tests/test_obs.py). ``stopwatch()`` always measures and exposes
-``duration_s`` — it records an event only when tracing is enabled, so hot
-paths that need the number (trainer watchdog, autotune trials) pay one
+Spans, stopwatches and counters record while tracing is enabled
+(``REPRO_TRACE``, :func:`enable`) or while a JAX profiler session records
+(``jax.profiler.start_trace`` to ``stop_trace``); :func:`enabled` returns
+that predicate. While a profiler session records, each span also enters a
+``jax.profiler.TraceAnnotation`` of its name and args, so it lands on the
+profiler's host plane, on the device trace's clock; Python's collector
+adds a ``py/gc`` span (generation) per collection. ``backends.get``'s
+auto-wrap (:mod:`~repro.obs.instrument`) follows the explicit flag alone:
+a profiler session never changes which objects the program builds.
+
+The tracer is zero-cost when not recording: ``span()`` returns a shared
+no-op context manager after one profiler query, without reading a clock
+(overhead asserted in tests/test_obs.py). ``stopwatch()`` always measures
+and exposes ``duration_s`` — it records an event only while recording, so
+hot paths that need the number (trainer watchdog, autotune trials) pay one
 clock pair either way, exactly as before.
 """
 from __future__ import annotations

@@ -9,7 +9,9 @@ the wrapper is substitutable anywhere a backend instance is — ``cp_als``,
 ``serve.offload_report``, the parity suite.
 
 ``backends.get`` auto-wraps constructed backends when tracing is enabled
-(see :func:`maybe_instrument`); an already-built instance passed through
+by the explicit flag (see :func:`maybe_instrument`); a JAX profiler
+session never changes which objects the program builds. An already-built
+instance passed through
 ``get`` is never wrapped implicitly — wrap explicitly with
 ``InstrumentedBackend(be)`` to opt in.
 """
@@ -100,8 +102,11 @@ class InstrumentedBackend(_backend_base()):
 
 
 def maybe_instrument(backend):
-    """Wrap ``backend`` iff tracing is enabled and it isn't wrapped already —
-    the hook ``backends.get`` calls on every backend it constructs."""
-    if _tracer.enabled() and not isinstance(backend, InstrumentedBackend):
+    """Wrap ``backend`` iff tracing is enabled by the explicit flag
+    (``REPRO_TRACE`` / ``enable()``, never a profiler session) and it isn't
+    wrapped already — the hook ``backends.get`` calls on every backend it
+    constructs."""
+    if (_tracer.get_tracer().enabled
+            and not isinstance(backend, InstrumentedBackend)):
         return InstrumentedBackend(backend)
     return backend

@@ -1,14 +1,24 @@
 """The tracer: nestable wall-clock spans + typed counters, exported as
-Chrome ``trace_event`` JSON.
+Chrome ``trace_event`` JSON, and mirrored onto the JAX profiler's clock.
 
 One process-global :class:`Tracer` instance backs the module-level front
 doors in :mod:`repro.obs` (``span`` / ``stopwatch`` / ``counter``). The
 design constraints, in order:
 
-* **zero-cost when disabled** — ``span()`` is a module-flag check plus the
-  return of one shared no-op context manager; no clock is read, no object
-  allocated, no lock taken. The overhead contract is tested
-  (tests/test_obs.py: a spanned hot loop must not regress vs un-spanned).
+* **one recording predicate** — spans, stopwatches and counters record
+  while tracing is enabled (``REPRO_TRACE`` / ``enable()``) *or* while a
+  JAX profiler session is recording (``jax.profiler.start_trace`` to
+  ``stop_trace``). :func:`enabled` returns that predicate.
+* **the device trace's clock** — while a profiler session records, a span
+  also enters a ``jax.profiler.TraceAnnotation`` of its name and args, so
+  it lands on the profiler's host plane beside the device's programs. The
+  in-memory event keeps the tracer's own epoch; read it for durations and
+  order.
+* **zero-cost when not recording** — ``span()`` is a flag check and one
+  profiler query (~0.1 us) plus the return of one shared no-op context
+  manager; no clock is read, no object allocated, no lock taken. The
+  overhead contract is tested (tests/test_obs.py: a spanned hot loop must
+  not regress vs un-spanned).
 * **always-correct timing when asked** — ``stopwatch()`` reads the clock
   whether or not tracing is enabled and exposes ``duration_s`` afterwards,
   so callers that *need* the measurement (the trainer's straggler watchdog,
@@ -20,15 +30,23 @@ design constraints, in order:
 
 Enabling: ``REPRO_TRACE`` in the environment (any value but ``0``/empty)
 enables tracing at import; ``enable()`` / ``disable()`` toggle it
-programmatically at any point.
+programmatically at any point. Python's garbage collector records a
+``py/gc`` span (arg: generation) per collection under the same predicate,
+so a collector pause that holds the device up names itself.
 """
 from __future__ import annotations
 
+import gc
 import json
 import os
 import threading
 import time
 from typing import Any
+
+from jax.profiler import TraceAnnotation
+
+# True only while a JAX profiler session records
+_profiling = TraceAnnotation.is_enabled
 
 # wall-clock spans record under this Chrome pid; virtual (cycle-domain)
 # timelines allocate their own pids via next_pid() so the two domains sit
@@ -60,7 +78,8 @@ class Stopwatch:
     measurement for the trainer / serve launcher / autotuner.
     """
 
-    __slots__ = ("tracer", "name", "args", "t0", "duration_s")
+    __slots__ = ("tracer", "name", "args", "t0", "duration_s", "_rec",
+                 "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self.tracer = tracer
@@ -68,8 +87,16 @@ class Stopwatch:
         self.args = args
         self.t0 = 0.0
         self.duration_s = 0.0
+        self._rec = False
+        self._ann = None
 
     def __enter__(self) -> "Stopwatch":
+        # whether it records is decided once, here
+        profiling = _profiling()
+        self._rec = self.tracer.enabled or profiling
+        if profiling:
+            self._ann = TraceAnnotation(self.name, **self.args)
+            self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
@@ -78,14 +105,17 @@ class Stopwatch:
 
     def __exit__(self, *exc):
         self.duration_s = time.perf_counter() - self.t0
-        if self.tracer.enabled:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        if self._rec:
             self.tracer._record(self.name, self.t0, self.duration_s,
                                 self.args)
         return False
 
 
 class _Span(Stopwatch):
-    """A recording span (only constructed when tracing is enabled)."""
+    """A recording span (only constructed while the tracer records)."""
 
     __slots__ = ()
 
@@ -94,35 +124,50 @@ class Tracer:
     """Collects spans and counters; renders Chrome ``trace_event`` JSON."""
 
     def __init__(self):
-        self.enabled = False
-        self._lock = threading.Lock()
+        self.enabled = False          # the explicit flag (REPRO_TRACE)
+        # re-entrant: a collection (the ``py/gc`` span) may start while
+        # this thread holds the lock
+        self._lock = threading.RLock()
         self._events: list[dict] = []
         self._counters: dict[str, float] = {}
         self._epoch = time.perf_counter()
         self._next_pid = 1
+        self._gc_span: Stopwatch | None = None   # the open ``py/gc`` span
 
     # -- recording ---------------------------------------------------------
 
     def span(self, name: str, **args: Any):
-        """A nestable span context manager — the shared no-op when tracing
-        is disabled (the zero-cost contract), a recording span otherwise."""
-        if not self.enabled:
+        """A nestable span context manager — the shared no-op when not
+        recording (the zero-cost contract), a recording span otherwise."""
+        if not (self.enabled or _profiling()):
             return _NULL_SPAN
         return _Span(self, name, args)
 
     def stopwatch(self, name: str, **args: Any) -> Stopwatch:
         """A span that ALWAYS measures (``duration_s`` after exit) and
-        records the event only when tracing is enabled."""
+        records the event only while the tracer records."""
         return Stopwatch(self, name, args)
 
     def counter(self, name: str, value: float = 1.0) -> None:
-        """Accumulate a named counter (no-op when disabled). Integer values
-        stay integers; floats stay floats — ``counters()`` returns whatever
-        type accumulated."""
-        if not self.enabled:
+        """Accumulate a named counter (no-op when not recording). Integer
+        values stay integers; floats stay floats — ``counters()`` returns
+        whatever type accumulated."""
+        if not (self.enabled or _profiling()):
             return
         with self._lock:
             self._counters[name] = self._counters.get(name, 0) + value
+
+    def on_gc(self, phase: str, info: dict) -> None:
+        """The ``gc.callbacks`` hook: one ``py/gc`` span per collection
+        (collections neither nest nor overlap), under the predicate."""
+        if phase == "start":
+            if self.enabled or _profiling():
+                self._gc_span = Stopwatch(self, "py/gc",
+                                          {"generation": info["generation"]})
+                self._gc_span.__enter__()
+        elif self._gc_span is not None:
+            sw, self._gc_span = self._gc_span, None
+            sw.__exit__(None, None, None)
 
     def _record(self, name: str, t0: float, dur_s: float, args: dict):
         ev = {
@@ -220,6 +265,7 @@ class Tracer:
 _TRACER = Tracer()
 if os.environ.get("REPRO_TRACE", "") not in ("", "0"):
     _TRACER.enabled = True
+gc.callbacks.append(_TRACER.on_gc)
 
 
 def get_tracer() -> Tracer:
@@ -235,7 +281,9 @@ def disable() -> None:
 
 
 def enabled() -> bool:
-    return _TRACER.enabled
+    """The recording predicate: tracing is enabled, or a JAX profiler
+    session records."""
+    return _TRACER.enabled or _profiling()
 
 
 def span(name: str, **args):

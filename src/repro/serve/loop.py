@@ -43,10 +43,13 @@ How the pieces fit:
   wall time, per batch — is recorded in ``ServeReport.offload`` and
   lands in the ``serve_*`` bench rows.
 
-Every phase is observable (`repro.obs`): spans ``serve/admit``,
-``serve/prefill``, ``serve/decode``, ``serve/offload``, ``serve/evict``;
-counters ``serve/admitted``, ``serve/rejected``, ``serve/preempted``,
-``serve/prefills``, ``serve/decode_steps``, ``serve/tokens``.
+Every phase is observable (`repro.obs`): spans ``serve/idle``,
+``serve/enqueue``, ``serve/admit``, ``serve/prefill``, ``serve/evict``,
+``serve/step`` (children ``serve/offload``, ``serve/decode/build``,
+``serve/decode``, ``serve/sample``) and ``serve/fail``; counters
+``serve/admitted``, ``serve/rejected``, ``serve/preempted``,
+``serve/failed``. The list, with each span's args, is in
+:mod:`repro.obs`.
 
 The loop is a single-consumer ``asyncio`` engine: a producer task releases
 requests at their (speedup-scaled) arrival times while the engine task
@@ -390,11 +393,13 @@ class ServeLoop:
 
         async def producer():
             for r in sorted(requests, key=lambda q: q.arrival_s):
-                delay = r.arrival_s / lc.speedup - now()
-                if delay > 0:
+                due = r.arrival_s / lc.speedup
+                while (delay := due - now()) > 0:
                     await asyncio.sleep(delay)
-                records[r.rid].arrival_s = now()
-                queue.append(r)
+                t = records[r.rid].arrival_s = now()
+                with obs.span("serve/enqueue", rid=r.rid,
+                              late_ms=1e3 * (t - due)):
+                    queue.append(r)
             done_producing.set()
 
         prod = asyncio.ensure_future(producer())
@@ -436,10 +441,17 @@ class ServeLoop:
             arr = records[rid].arrival_s
             return arr is not None and now() - arr > lc.deadline_s
 
+        # one span per idle stretch: opened by an iteration that finds
+        # nothing queued or active, closed when work arrives
+        idle = None
         try:
             while not (done_producing.is_set() and not queue
                        and all(a is None for a in active)):
                 progressed = False
+                if idle is not None and (queue or len(free_rows)
+                                         < lc.max_batch):
+                    idle.__exit__(None, None, None)
+                    idle = None
 
                 # -- deadlines: shed overdue queued work, time out live rows
                 if lc.deadline_s is not None:
@@ -473,8 +485,6 @@ class ServeLoop:
                             tok = self._prefill_one(req)
                         if rec.first_token_s is None:
                             rec.first_token_s = now()
-                        obs.counter("serve/prefills")
-                        obs.counter("serve/tokens")
                         n_prefills += 1
                         a = _Active(req=req, row=free_rows.pop(),
                                     admit_seq=admit_seq, next_token=tok,
@@ -514,62 +524,25 @@ class ServeLoop:
                         step_rows.pop()
 
                 if step_rows:
-                    b = len(step_rows)
-                    with obs.span("serve/offload", batch=b):
-                        decision = self.scheduler.decide_decode(self.cfg, b)
-
-                    s_v = self._bucket(max(a.pos for a in step_rows))
-                    token = np.zeros(lc.max_batch, np.int32)
-                    cache_pos = np.zeros(lc.max_batch, np.int32)
-                    gather_idx = np.full((lc.max_batch, s_v), self._pad_slot,
-                                         np.int32)
-                    new_slots = np.full(lc.max_batch, self._pad_slot, np.int32)
-                    for a in step_rows:
-                        slots = self.kv.physical_slots(a.req.rid)
-                        gather_idx[a.row, :a.pos] = slots[:a.pos]
-                        new_slots[a.row] = slots[a.pos]
-                        token[a.row] = a.next_token
-                        cache_pos[a.row] = a.pos
-
-                    with obs.stopwatch("serve/decode", batch=b,
-                                       view=s_v) as sw:
-                        logits, self.slab = self._decode_fn(
-                            self.params, self.slab, jnp.asarray(token),
-                            jnp.asarray(cache_pos), jnp.asarray(gather_idx),
-                            jnp.asarray(new_slots))
-                        logits_np = np.asarray(logits)
-                    self.scheduler.observe_host(b, sw.duration_s)
-                    offload_log.append({
-                        "batch": b,
-                        "target": decision.target,
-                        "modeled_s": decision.modeled_s,
-                        "host_ema_s": decision.host_s,
-                        "measured_s": sw.duration_s,
-                        "makespan_cycles": decision.price.makespan_cycles,
-                        "n_arrays": decision.price.n_arrays,
-                    })
+                    with obs.span("serve/step", batch=len(step_rows)):
+                        self._step(step_rows, offload_log, finish)
                     n_steps += 1
-                    obs.counter("serve/decode_steps")
-
-                    next_tok = self._sample(logits_np)
-                    for a in step_rows:
-                        a.pos += 1
-                        t = int(next_tok[a.row])
-                        a.next_token = t
-                        a.generated.append(t)
-                        obs.counter("serve/tokens")
-                        if len(a.generated) >= a.req.decode_len:
-                            finish(a)
                     progressed = True
 
                 util = self.kv.utilization()
                 peak_util = max(peak_util, util)
                 frag_sum += self.kv.fragmentation()
                 frag_n += 1
+                if (idle is None and not progressed and not queue
+                        and len(free_rows) == lc.max_batch):
+                    idle = obs.span("serve/idle")
+                    idle.__enter__()
                 # yield so the producer can enqueue between steps
                 await asyncio.sleep(0 if progressed else lc.idle_poll_s)
             await prod
         finally:
+            if idle is not None:
+                idle.__exit__(None, None, None)
             if not prod.done():
                 prod.cancel()
 
@@ -585,6 +558,55 @@ class ServeLoop:
             offload=offload_log,
             speedup=lc.speedup,
         )
+
+    def _step(self, step_rows, offload_log: list, finish) -> None:
+        """One decode step of the live rows: price the offload, build the
+        host index arrays, run the step, sample and advance every row."""
+        lc = self.loop_cfg
+        b = len(step_rows)
+        with obs.span("serve/offload", batch=b):
+            decision = self.scheduler.decide_decode(self.cfg, b)
+
+        s_v = self._bucket(max(a.pos for a in step_rows))
+        with obs.span("serve/decode/build", batch=b, view=s_v):
+            token = np.zeros(lc.max_batch, np.int32)
+            cache_pos = np.zeros(lc.max_batch, np.int32)
+            gather_idx = np.full((lc.max_batch, s_v), self._pad_slot,
+                                 np.int32)
+            new_slots = np.full(lc.max_batch, self._pad_slot, np.int32)
+            for a in step_rows:
+                slots = self.kv.physical_slots(a.req.rid)
+                gather_idx[a.row, :a.pos] = slots[:a.pos]
+                new_slots[a.row] = slots[a.pos]
+                token[a.row] = a.next_token
+                cache_pos[a.row] = a.pos
+
+        with obs.stopwatch("serve/decode", batch=b, view=s_v) as sw:
+            logits, self.slab = self._decode_fn(
+                self.params, self.slab, jnp.asarray(token),
+                jnp.asarray(cache_pos), jnp.asarray(gather_idx),
+                jnp.asarray(new_slots))
+            logits_np = np.asarray(logits)
+        self.scheduler.observe_host(b, sw.duration_s)
+        offload_log.append({
+            "batch": b,
+            "target": decision.target,
+            "modeled_s": decision.modeled_s,
+            "host_ema_s": decision.host_s,
+            "measured_s": sw.duration_s,
+            "makespan_cycles": decision.price.makespan_cycles,
+            "n_arrays": decision.price.n_arrays,
+        })
+
+        with obs.span("serve/sample", batch=b):
+            next_tok = self._sample(logits_np)
+            for a in step_rows:
+                a.pos += 1
+                t = int(next_tok[a.row])
+                a.next_token = t
+                a.generated.append(t)
+                if len(a.generated) >= a.req.decode_len:
+                    finish(a)
 
     def run_sync(self, requests) -> ServeReport:
         return asyncio.run(self.run(requests))

@@ -3,6 +3,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro import obs
 from repro.core.cp_als import cp_als, cp_als_psram, reconstruct
 from repro.core.mttkrp import dense_to_coo
 from repro.data.tensors import lowrank_dense, sparse_coo
@@ -57,3 +58,31 @@ def test_als_on_sampled_sparse(key):
                   key=jax.random.PRNGKey(13), tol=0)
     assert st25.fit > st2.fit
     assert st25.fit > 0.05
+
+
+def test_cp_als_spans_prepare_and_fit_read(key):
+    """One ``als/prepare`` ends before the first sweep starts, and every
+    ``als/fit`` holds the host read of its fit."""
+    idx, vals, shape = sparse_coo(key, (12, 10, 8), nnz=300, rank=3)
+    obs.get_tracer().clear()
+    obs.enable()
+    try:
+        st = cp_als(None, rank=3, n_iter=4, coo=(idx, vals, shape),
+                    key=jax.random.PRNGKey(2), tol=0)
+        events = obs.get_tracer().events()
+    finally:
+        obs.disable()
+        obs.get_tracer().clear()
+
+    def named(name):
+        return sorted((e for e in events if e["name"] == name),
+                      key=lambda e: e["ts"])
+
+    prepare, sweeps = named("als/prepare"), named("als/sweep")
+    fits, reads = named("als/fit"), named("als/fit/read")
+    assert len(prepare) == 1 and len(sweeps) == st.iters == 4
+    assert prepare[0]["ts"] + prepare[0]["dur"] <= sweeps[0]["ts"]
+    assert len(fits) == len(reads) == st.iters
+    for fit, read in zip(fits, reads):
+        assert fit["ts"] <= read["ts"]
+        assert read["ts"] + read["dur"] <= fit["ts"] + fit["dur"] + 1e-3

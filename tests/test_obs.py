@@ -4,8 +4,11 @@ Every test leaves the global tracer disabled and empty: the tracer is
 process-global state, and a leaked enable would silently wrap every backend
 the rest of the suite constructs.
 """
+import contextlib
+import gc
 import json
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +31,12 @@ def _clean_tracer():
 # ------------------------------------------------------------------ tracer
 
 
+def _without_gc(events):
+    """The events a test made: an automatic collection may add ``py/gc``
+    spans anywhere while the tracer records."""
+    return [e for e in events if e["name"] != "py/gc"]
+
+
 def test_span_records_events_and_counters():
     obs.enable()
     with obs.span("test/outer", k=3):
@@ -35,7 +44,7 @@ def test_span_records_events_and_counters():
             pass
         obs.counter("test/widgets", 2.0)
         obs.counter("test/widgets", 1.0)
-    events = obs.get_tracer().events()
+    events = _without_gc(obs.get_tracer().events())
     names = [e["name"] for e in events]
     assert names == ["test/inner", "test/outer"]  # closed in LIFO order
     outer = events[1]
@@ -119,7 +128,106 @@ def test_stopwatch_measures_even_when_disabled():
     with obs.stopwatch("test/sw") as sw:
         pass
     assert sw.duration_s >= 0.0
-    assert [e["name"] for e in obs.get_tracer().events()] == ["test/sw"]
+    assert [e["name"] for e in _without_gc(obs.get_tracer().events())] == [
+        "test/sw"]
+
+
+# ------------------------------------------------- the profiler's clock
+
+
+@contextlib.contextmanager
+def _profiler_session(path):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(path), profiler_options=opts)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+
+
+def _host_events(path):
+    """``(name, start_ns, duration_ns, stats)`` of every event on the
+    profiler's host planes."""
+    from jax.profiler import ProfileData
+
+    pb = sorted(Path(path).glob("**/*.xplane.pb"))[-1]
+    pd = ProfileData.from_file(str(pb))
+    return [(e.name, e.start_ns, e.duration_ns, dict(e.stats))
+            for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+def _double(x):
+    return 2 * x
+
+
+def test_span_lands_on_profiler_host_plane(tmp_path):
+    """Inside a profiler session (tracer flag off) a span records: on the
+    host plane under its bare name with its args as stats, around the
+    host events of the jitted call it holds, and in the tracer."""
+    double = jax.jit(_double)
+    x = jnp.ones(8)
+    double(x).block_until_ready()                  # compiled outside
+    assert not obs.enabled()
+    with _profiler_session(tmp_path):
+        assert obs.enabled()
+        with obs.span("test/host", batch=3, view=128):
+            double(x).block_until_ready()
+    host = _host_events(tmp_path)
+    spans = [e for e in host if e[0] == "test/host"]
+    assert len(spans) == 1
+    _, t0, dur, stats = spans[0]
+    assert stats == {"batch": 3, "view": 128}
+    calls = [e for e in host if e[0] == "PjitFunction(_double)"]
+    assert calls
+    assert all(t0 <= c[1] and c[1] + c[2] <= t0 + dur for c in calls)
+    recorded = _without_gc(obs.get_tracer().events())
+    assert [e["name"] for e in recorded] == ["test/host"]
+    assert recorded[0]["args"] == {"batch": 3, "view": 128}
+
+
+def test_nothing_records_after_stop_trace(tmp_path):
+    with _profiler_session(tmp_path):
+        with obs.span("test/in"):
+            pass
+        with obs.stopwatch("test/sw_in"):
+            pass
+        if obs.enabled():                           # the guard idiom counts
+            obs.counter("test/n")
+    assert not obs.enabled()
+    assert obs.span("test/out") is obs.span("test/other", a=1)  # shared null
+    with obs.span("test/out"):
+        pass
+    with obs.stopwatch("test/sw_out") as sw:
+        pass
+    assert sw.duration_s >= 0.0
+    obs.counter("test/n")
+    names = [e["name"] for e in _without_gc(obs.get_tracer().events())]
+    assert names == ["test/in", "test/sw_in"]
+    assert obs.get_tracer().counters() == {"test/n": 1.0}
+
+
+def test_gc_collection_records_a_span(tmp_path):
+    gc.collect()                                    # neither on: nothing
+    assert obs.get_tracer().events() == []
+    with _profiler_session(tmp_path):
+        gc.collect()
+    mine = [e for e in obs.get_tracer().events()
+            if e["name"] == "py/gc" and e["args"] == {"generation": 2}]
+    assert mine                        # the forced one, perhaps not alone
+    assert any(e[0] == "py/gc" and e[3] == {"generation": 2}
+               for e in _host_events(tmp_path))
+
+
+def test_profiler_session_builds_no_instrumented_backend(tmp_path):
+    from repro.obs.instrument import InstrumentedBackend
+
+    with _profiler_session(tmp_path):
+        assert obs.enabled()
+        be = backends.get("exact")
+    assert not isinstance(be, InstrumentedBackend)
 
 
 # ---------------------------------------------------------- virtual timeline

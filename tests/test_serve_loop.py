@@ -6,6 +6,7 @@ gather/scatter) must produce exactly the greedy tokens the dense
 ``ServeEngine.generate`` produces per request — same weights, same prompts.
 """
 import asyncio
+import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -232,11 +233,87 @@ def test_loop_preempts_youngest_under_page_pressure(arch, params):
         counters = obs.get_tracer().counters()
         assert counters["serve/preempted"] == rep.preemptions
         assert counters["serve/admitted"] >= 5 + rep.preemptions
+        # tokens, prefills and steps are the report's, not counters
+        assert not {"serve/prefills", "serve/decode_steps",
+                    "serve/tokens"} & set(counters)
         names = {e["name"] for e in obs.get_tracer().events()}
         assert {"serve/admit", "serve/prefill", "serve/decode",
-                "serve/offload", "serve/evict"} <= names
+                "serve/offload", "serve/evict", "serve/step",
+                "serve/decode/build", "serve/sample",
+                "serve/enqueue"} <= names
     finally:
         obs.disable()
+        obs.get_tracer().clear()
+
+
+def _inside(inner, outer, eps_us=1e-3):
+    """Whether ``inner`` lies within ``outer`` (µs, rounding allowed)."""
+    return (outer["ts"] - eps_us <= inner["ts"] and inner["ts"] + inner["dur"]
+            <= outer["ts"] + outer["dur"] + eps_us)
+
+
+@pytest.fixture(scope="module")
+def spaced_run(arch, params):
+    """A warmed loop over three short requests due 0.4 s apart, traced:
+    each finishes long before the next is due, so the loop goes idle
+    before each arrival (the first included: the engine's first iteration
+    runs before the producer)."""
+    reqs = traffic.generate(TrafficConfig(
+        n_requests=3, seed=4, rate_rps=100.0, prompt_min=4, prompt_max=8,
+        decode_min=3, decode_max=3, vocab_size=arch.vocab_size))
+    reqs = [dataclasses.replace(r, arrival_s=0.4 * i)
+            for i, r in enumerate(reqs)]
+    loop = _loop(arch, params, speedup=1.0)
+    loop.warmup(max_prompt=8, max_decode=3)
+    obs.get_tracer().clear()
+    obs.enable()
+    try:
+        rep = loop.run_sync(reqs)
+        events = obs.get_tracer().events()
+    finally:
+        obs.disable()
+        obs.get_tracer().clear()
+    return reqs, rep, events
+
+
+def _named(events, name):
+    return [e for e in events if e["name"] == name]
+
+
+def test_loop_records_one_idle_span_per_idle_stretch(spaced_run):
+    reqs, rep, events = spaced_run
+    recs = rep.records
+    assert len(rep.completed) == 3
+    # the premise: every request finished before the next was released
+    for prev, nxt in zip(recs, recs[1:]):
+        assert prev.finished_s < nxt.arrival_s
+    idle = _named(events, "serve/idle")
+    enqueue = _named(events, "serve/enqueue")
+    assert len(idle) == len(reqs)             # one per stretch, not per poll
+    for span in idle:                          # each ended by one arrival
+        assert sum(_inside(e, span) for e in enqueue) == 1
+    assert not any(_inside(e, span) for span in idle
+                   for e in _named(events, "serve/step"))
+
+
+def test_loop_enqueue_span_carries_rid_and_lateness(spaced_run):
+    reqs, _, events = spaced_run
+    enqueue = _named(events, "serve/enqueue")
+    assert sorted(e["args"]["rid"] for e in enqueue) == sorted(
+        r.rid for r in reqs)
+    assert all(e["args"]["late_ms"] >= 0.0 for e in enqueue)
+
+
+def test_loop_step_span_holds_one_decode(spaced_run):
+    _, rep, events = spaced_run
+    steps = _named(events, "serve/step")
+    assert len(steps) == rep.n_steps > 0
+    for child in ("serve/decode", "serve/offload", "serve/decode/build",
+                  "serve/sample"):
+        spans = _named(events, child)
+        assert len(spans) == len(steps)
+        for step in steps:
+            assert sum(_inside(e, step) for e in spans) == 1
 
 
 def test_loop_preemption_cap_fails_cleanly(arch, params):

@@ -96,7 +96,7 @@ def resolve_stream_lowering(backend: str = "auto") -> str:
     ("the last two dimensions of your block shape are divisible by 8 and
     128 respectively, or be equal to the respective dimensions of the
     overall array"). With that block made whole, Mosaic refuses the
-    data-dependent factor-row gather ``qs[d][idx]`` inside the kernel body
+    data-dependent factor-row gather ``ps[d][idx]`` inside the kernel body
     ("Shape mismatch in input, indices and output"), and the scatter
     ``out_ref[...].at[sp].add`` is of the same kind. The ``"xla"`` lowering
     runs the same ``_chunk_partials`` body as a ``lax.scan`` and compiles

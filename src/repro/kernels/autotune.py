@@ -185,14 +185,14 @@ def stream_params(csf, factors, config: PsramConfig, tune: bool = False,
     from repro.sparse.stream import stream_layout
 
     mode = csf.mode_order[0]
-    qs, ss = stream_factor_quants(tuple(factors), mode)
+    ps = stream_factor_quants(tuple(factors), mode)
     fn = _LOWERING_FNS[lowering]
 
     def measure(params):
         ip, vp, lp, sp, n_seg = stream_layout(
             csf, config.rows, params["exec_blocks"])
         ip = ip.astype(jnp.int32)
-        return lambda: fn(ip, vp, lp, sp, qs, ss, mode, n_seg, adc_bits,
+        return lambda: fn(ip, vp, lp, sp, ps, mode, n_seg, adc_bits,
                           csf.shape[mode])
 
     return get_params(key, measure=measure, tune=True)

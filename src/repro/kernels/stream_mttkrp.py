@@ -8,9 +8,11 @@ the pSRAM path — a per-product quantize/ADC pass. This module fuses the
 whole per-chunk pipeline into one kernel body:
 
 1. **int8 factor-row gathers** (CP 1/2): the non-target factors are
-   pre-quantized per row (``quantize_symmetric(f, axis=-1)``), so each
-   nonzero gathers ``R`` int8 values per factor instead of ``R`` f32 —
-   a 4x cut of the gather traffic that dominates the stream executor.
+   pre-quantized per row (``quantize_symmetric(f, axis=-1)``) and stored
+   as ``(J, R + 4)`` int8 rows, the ``R`` codes followed by the row's f32
+   scale bit-cast into four int8 lanes. One row gather per factor per
+   nonzero fetches ``R + 4`` bytes, codes and scale together, instead of
+   ``R`` f32 values — the gather traffic dominates the stream executor.
 2. **exact integer Hadamard chain**: two-factor chains multiply the int8
    gathers in int16 (``|q1*q2| <= 127^2 < 2^15``) and convert once to f32;
    the *combined* scale ``prod_d s_d[idx_d] * value`` is folded into the
@@ -57,27 +59,43 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import obs
 from repro.core.quantization import adc_transfer, quantize_symmetric
 
 
-def quantize_stream_factors(factors, mode: int):
-    """Per-row int8 quantization of the non-target factors.
+SCALE_LANES = 4        # int8 lanes that hold one f32 row scale
 
-    Returns ``(qs, ss)`` tuples ordered like ``factors`` with the target
-    mode's slots holding size-(1,1) placeholders (never gathered — the
-    chain skips ``mode``); per-row scales keep the quantization envelope
-    identical to ``cp_chain_psram``'s factor treatment.
+
+def pack_rows(q, s):
+    """``(J, R)`` int8 codes and ``(J, 1)`` f32 scales -> ``(J, R + 4)``
+    int8 rows: each row's codes, then its scale's four bytes."""
+    sb = jax.lax.bitcast_convert_type(s.astype(jnp.float32), jnp.int8)
+    return jnp.concatenate([q, sb.reshape(q.shape[0], SCALE_LANES)], axis=-1)
+
+
+def unpack_rows(g):
+    """Inverse of ``pack_rows`` on gathered rows ``(..., R + 4)``: the
+    int8 codes ``(..., R)`` and the f32 scales ``(...)``, bit for bit."""
+    return (g[..., :-SCALE_LANES],
+            jax.lax.bitcast_convert_type(g[..., -SCALE_LANES:], jnp.float32))
+
+
+def quantize_stream_factors(factors, mode: int):
+    """Per-row int8 quantization of the non-target factors, packed.
+
+    Returns a tuple ordered like ``factors`` of ``(J, R + 4)`` int8 arrays
+    (``pack_rows``), with the target mode's slot holding a size-(1,1)
+    placeholder (never gathered — the chain skips ``mode``); per-row
+    scales keep the quantization envelope identical to
+    ``cp_chain_psram``'s factor treatment.
     """
-    qs, ss = [], []
+    ps = []
     for d, f in enumerate(factors):
         if d == mode:
-            qs.append(jnp.zeros((1, 1), jnp.int8))
-            ss.append(jnp.zeros((1, 1), jnp.float32))
+            ps.append(jnp.zeros((1, 1), jnp.int8))
         else:
-            q, s = quantize_symmetric(f, axis=-1)
-            qs.append(q)
-            ss.append(s.astype(jnp.float32))
-    return tuple(qs), tuple(ss)
+            ps.append(pack_rows(*quantize_symmetric(f, axis=-1)))
+    return tuple(ps)
 
 
 _quantize_stream_factors_jit = jax.jit(
@@ -104,7 +122,7 @@ def stream_factor_quants(factors, mode: int):
     return val
 
 
-def _chunk_partials(ip_c, vp_c, lp_c, qs, ss, *, mode, n_seg, adc_bits):
+def _chunk_partials(ip_c, vp_c, lp_c, ps, *, mode, n_seg, adc_bits):
     """The fused body for ONE execution chunk — shared verbatim by every
     lowering (the Pallas kernel calls it on refs' values, the XLA scan on
     its per-step slices, the flat oracle on the full stack).
@@ -112,6 +130,7 @@ def _chunk_partials(ip_c, vp_c, lp_c, qs, ss, *, mode, n_seg, adc_bits):
     ip_c: (E, rows, nmodes) int32 nonzero coordinates
     vp_c: (E, rows) f32 nonzero values (0.0 padding)
     lp_c: (E, rows) int32 block-local segment ids
+    ps:   per mode, (J, R + 4) packed int8 factor rows (``pack_rows``)
     Returns (E, n_seg, R) ADC-digitized per-segment partials.
     """
     nmodes = ip_c.shape[-1]
@@ -122,10 +141,10 @@ def _chunk_partials(ip_c, vp_c, lp_c, qs, ss, *, mode, n_seg, adc_bits):
     had = None
     scale = vp_c                                        # (E, rows)
     for d in others:
-        idx = ip_c[..., d]
-        g = qs[d][idx]                                  # (E, rows, R) int8 gather
+        # one gather brings the row's codes and its scale: (E, rows, R+4)
+        g, s = unpack_rows(ps[d][ip_c[..., d]])
         had = g.astype(acc_t) if had is None else had * g.astype(acc_t)
-        scale = scale * ss[d][idx, 0]
+        scale = scale * s
     had = had.astype(jnp.float32)
     rows = had.shape[-2]
     sids = jax.lax.broadcasted_iota(jnp.int32, (1, n_seg, rows), 1)
@@ -146,23 +165,26 @@ def _chunk_partials(ip_c, vp_c, lp_c, qs, ss, *, mode, n_seg, adc_bits):
     return parts
 
 
+def _rank(ps, mode):
+    """R of the packed ``(J, R + 4)`` factor rows."""
+    width = next(p.shape[-1] for d, p in enumerate(ps) if d != mode)
+    return width - SCALE_LANES
+
+
 # --------------------------------------------------------------- Pallas
 
 
 def _stream_kernel(ip_ref, vp_ref, lp_ref, sp_ref, *rest, mode, n_seg,
                    adc_bits, nmodes):
-    qs_refs, ss_refs = rest[:nmodes], rest[nmodes:2 * nmodes]
-    out_ref = rest[2 * nmodes]
+    ps_refs, out_ref = rest[:nmodes], rest[nmodes]
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    qs = tuple(r[...] for r in qs_refs)
-    ss = tuple(r[...] for r in ss_refs)
     parts = _chunk_partials(
-        ip_ref[0], vp_ref[0], lp_ref[0], qs, ss,
+        ip_ref[0], vp_ref[0], lp_ref[0], tuple(r[...] for r in ps_refs),
         mode=mode, n_seg=n_seg, adc_bits=adc_bits,
     )
     rank = parts.shape[-1]
@@ -171,21 +193,20 @@ def _stream_kernel(ip_ref, vp_ref, lp_ref, sp_ref, *rest, mode, n_seg,
 
 @functools.partial(jax.jit, static_argnames=(
     "mode", "n_seg", "adc_bits", "out_rows", "interpret"))
-def stream_mttkrp_fused_pallas(ip, vp, lp, sp, qs, ss, mode, n_seg,
+def stream_mttkrp_fused_pallas(ip, vp, lp, sp, ps, mode, n_seg,
                                adc_bits, out_rows, interpret=False):
     """The ``pallas_call`` lowering: grid over chunks, output accumulator
     ref as the electrical cross-block carry, factors VMEM-resident, the
     per-chunk operand blocks prefetched by the grid pipeline."""
     nb, e, rows, nmodes = ip.shape
-    rank = next(q.shape[-1] for d, q in enumerate(qs) if d != mode)
+    rank = _rank(ps, mode)
     in_specs = [
         pl.BlockSpec((1, e, rows, nmodes), lambda i: (i, 0, 0, 0)),
         pl.BlockSpec((1, e, rows), lambda i: (i, 0, 0)),
         pl.BlockSpec((1, e, rows), lambda i: (i, 0, 0)),
         pl.BlockSpec((1, e * n_seg), lambda i: (i, 0)),
     ]
-    for arrs in (qs, ss):
-        in_specs += [pl.BlockSpec(a.shape, lambda i: (0, 0)) for a in arrs]
+    in_specs += [pl.BlockSpec(p.shape, lambda i: (0, 0)) for p in ps]
     out = pl.pallas_call(
         functools.partial(_stream_kernel, mode=mode, n_seg=n_seg,
                           adc_bits=adc_bits, nmodes=nmodes),
@@ -194,7 +215,7 @@ def stream_mttkrp_fused_pallas(ip, vp, lp, sp, qs, ss, mode, n_seg,
         out_specs=pl.BlockSpec((out_rows + 1, rank), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((out_rows + 1, rank), jnp.float32),
         interpret=interpret,
-    )(ip, vp, lp, sp, *qs, *ss)
+    )(ip, vp, lp, sp, *ps)
     return out[:out_rows]
 
 
@@ -205,7 +226,7 @@ def stream_mttkrp_fused_pallas(ip, vp, lp, sp, qs, ss, mode, n_seg,
 def fused_stream_executor(mode: int, n_seg: int, adc_bits: int,
                           out_rows: int):
     """The jitted XLA lowering for one static signature: ``fn(ip, vp, lp,
-    sp, qs, ss) -> (out_rows, R)``.
+    sp, ps) -> (out_rows, R)``.
 
     Cached with the PR 5 keying discipline: equal-by-value static keys
     return the *identical* callable (and with it XLA's compilation cache
@@ -215,13 +236,13 @@ def fused_stream_executor(mode: int, n_seg: int, adc_bits: int,
     """
 
     @jax.jit
-    def run(ip, vp, lp, sp, qs, ss):
-        rank = next(q.shape[-1] for d, q in enumerate(qs) if d != mode)
+    def run(ip, vp, lp, sp, ps):
+        rank = _rank(ps, mode)
 
         def step(out, blk):
             ip_c, vp_c, lp_c, sp_c = blk
             parts = _chunk_partials(
-                ip_c, vp_c, lp_c, qs, ss,
+                ip_c, vp_c, lp_c, ps,
                 mode=mode, n_seg=n_seg, adc_bits=adc_bits,
             )
             return out.at[sp_c].add(parts.reshape(-1, rank)), None
@@ -233,10 +254,10 @@ def fused_stream_executor(mode: int, n_seg: int, adc_bits: int,
     return run
 
 
-def stream_mttkrp_fused_xla(ip, vp, lp, sp, qs, ss, mode, n_seg, adc_bits,
+def stream_mttkrp_fused_xla(ip, vp, lp, sp, ps, mode, n_seg, adc_bits,
                             out_rows):
     return fused_stream_executor(mode, n_seg, adc_bits, out_rows)(
-        ip, vp, lp, sp, qs, ss)
+        ip, vp, lp, sp, ps)
 
 
 # ------------------------------------------------------------------ ref
@@ -244,14 +265,14 @@ def stream_mttkrp_fused_xla(ip, vp, lp, sp, qs, ss, mode, n_seg, adc_bits,
 
 @functools.partial(jax.jit, static_argnames=(
     "mode", "n_seg", "adc_bits", "out_rows"))
-def stream_mttkrp_fused_ref(ip, vp, lp, sp, qs, ss, mode, n_seg, adc_bits,
+def stream_mttkrp_fused_ref(ip, vp, lp, sp, ps, mode, n_seg, adc_bits,
                             out_rows):
     """Flat oracle: all chunks at once (vmapped body), one scatter. Same
     arithmetic as the scan/grid lowerings with the adds reassociated — the
     parity anchor, not a racer."""
     parts = jax.vmap(
         lambda i_c, v_c, l_c: _chunk_partials(
-            i_c, v_c, l_c, qs, ss, mode=mode, n_seg=n_seg,
+            i_c, v_c, l_c, ps, mode=mode, n_seg=n_seg,
             adc_bits=adc_bits)
     )(ip, vp, lp)                                       # (nb, E, n_seg, R)
     rank = parts.shape[-1]
@@ -299,6 +320,7 @@ def fused_stream_mttkrp(csf, factors, config=None, adc_bits: int = 16,
     if exec_blocks is None:
         exec_blocks = stream_params(csf, tuple(factors), cfg)["exec_blocks"]
     ip, vp, lp, sp, n_seg = stream_layout(csf, cfg.rows, exec_blocks)
-    qs, ss = stream_factor_quants(tuple(factors), mode)
-    return fn(ip, vp, lp, sp, qs, ss, mode, n_seg,
-              adc_bits, csf.shape[mode])
+    ps = stream_factor_quants(tuple(factors), mode)
+    if obs.enabled():
+        obs.counter("stream/row_gathers", csf.nnz * (len(csf.shape) - 1))
+    return fn(ip, vp, lp, sp, ps, mode, n_seg, adc_bits, csf.shape[mode])

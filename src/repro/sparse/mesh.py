@@ -241,16 +241,15 @@ def _mesh_executor(mesh: Mesh, lowering: str, mode: int, out_rows: int,
 
         in_specs = (P(axis), P(axis), P(axis), P(axis), P())
     elif lowering == "fused":
-        from repro.kernels.stream_mttkrp import _chunk_partials
+        from repro.kernels.stream_mttkrp import _chunk_partials, _rank
 
-        def device_fn(ip, vp, lp, sp, quants):
+        def device_fn(ip, vp, lp, sp, ps):
             ip, vp, lp, sp = ip[0], vp[0], lp[0], sp[0]
-            qs, ss = quants
-            rank = next(q.shape[-1] for d, q in enumerate(qs) if d != mode)
+            rank = _rank(ps, mode)
 
             def step(out, blk):
                 i_b, v_b, l_b, s_b = blk
-                parts = _chunk_partials(i_b, v_b, l_b, qs, ss, mode=mode,
+                parts = _chunk_partials(i_b, v_b, l_b, ps, mode=mode,
                                         n_seg=n_seg, adc_bits=adc_bits)
                 return out.at[s_b].add(parts.reshape(-1, rank)), None
 
@@ -328,10 +327,10 @@ def mesh_stream_mttkrp(
         if lowering == "fused":
             from repro.kernels.stream_mttkrp import stream_factor_quants
 
-            quants = stream_factor_quants(tuple(factors), mode)
+            ps = stream_factor_quants(tuple(factors), mode)
             fn = _mesh_executor(mesh, lowering, mode, out_rows, n_seg, psram,
                                 adc_bits)
-            return fn(ip, vp, lp, sp, quants)
+            return fn(ip, vp, lp, sp, ps)
         fn = _mesh_executor(mesh, lowering, mode, out_rows, n_seg, psram,
                             adc_bits)
         return fn(ip, vp, lp, sp, tuple(factors))

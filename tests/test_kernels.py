@@ -346,6 +346,92 @@ def test_fused_stream_exec_blocks_invariant():
         assert rel < 1e-3
 
 
+def _two_gather_partials(ip_c, vp_c, lp_c, qs, ss, *, mode, n_seg, adc_bits):
+    """The reference formula for the fused chunk body: per factor, one
+    gather of the ``(J, R)`` int8 codes and a second of the ``(J, 1)`` f32
+    row scales, then the same chain, mask, contraction and ADC epilogue."""
+    from repro.core.quantization import adc_transfer
+    others = [d for d in range(ip_c.shape[-1]) if d != mode]
+    acc_t = jnp.int16 if len(others) <= 2 else jnp.float32
+    had, scale = None, vp_c
+    for d in others:
+        idx = ip_c[..., d]
+        g = qs[d][idx]
+        had = g.astype(acc_t) if had is None else had * g.astype(acc_t)
+        scale = scale * ss[d][idx, 0]
+    had = had.astype(jnp.float32)
+    sids = jax.lax.broadcasted_iota(jnp.int32, (1, n_seg, had.shape[-2]), 1)
+    mask = (sids == lp_c[:, None, :]).astype(jnp.float32) * scale[:, None, :]
+    parts = jax.lax.dot_general(mask, had, (((2,), (1,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32)
+    if adc_bits:
+        full_scale = jnp.maximum(jnp.max(jnp.abs(parts)), 1e-30)
+        parts = adc_transfer(parts, 2 ** adc_bits, full_scale)
+    return parts
+
+
+@pytest.mark.parametrize("adc_bits", [0, 16])
+@pytest.mark.parametrize("shape,mode", [
+    ((30, 24, 18), 0),
+    ((30, 24, 18), 1),
+    ((30, 24, 18), 2),
+    ((12, 10, 9, 8), 1),       # three-factor chain: the f32 Hadamard
+])
+def test_packed_rows_match_two_gather_body(shape, mode, adc_bits):
+    """One gather of the packed ``(J, R + 4)`` rows gives, bit for bit, the
+    partials of the two-gather body on the same ``quantize_symmetric``
+    codes and scales; unpacking the packed factors gives those codes and
+    scales back exactly."""
+    from repro.core.psram import PsramConfig
+    from repro.kernels.stream_mttkrp import (
+        _chunk_partials, quantize_stream_factors, unpack_rows)
+    from repro.sparse import csf_for_mode, powerlaw_coo
+    from repro.sparse.stream import stream_layout
+    coo = powerlaw_coo(jax.random.PRNGKey(3), shape, nnz=2000, rank=4,
+                       alpha=1.1, mode=mode)
+    csf = csf_for_mode(coo, mode)
+    fs = tuple(jax.random.normal(jax.random.PRNGKey(d + 1), (s, 6))
+               for d, s in enumerate(shape))
+    ip, vp, lp, _, n_seg = stream_layout(csf, PsramConfig().rows, 2)
+    ps = quantize_stream_factors(fs, mode)
+    qs, ss = list(ps), list(ps)
+    for d, f in enumerate(fs):
+        if d == mode:
+            continue
+        qs[d], ss[d] = quantize_symmetric(f, axis=-1)
+        codes, scales = unpack_rows(ps[d])
+        np.testing.assert_array_equal(np.asarray(codes), np.asarray(qs[d]))
+        np.testing.assert_array_equal(np.asarray(scales),
+                                      np.asarray(ss[d][:, 0]))
+    kw = dict(mode=mode, n_seg=n_seg, adc_bits=adc_bits)
+    want = jax.jit(jax.vmap(
+        lambda i, v, l: _two_gather_partials(i, v, l, qs, ss, **kw)))(ip, vp, lp)
+    got = jax.jit(jax.vmap(
+        lambda i, v, l: _chunk_partials(i, v, l, ps, **kw)))(ip, vp, lp)
+    assert float(jnp.max(jnp.abs(want))) > 0
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_fused_stream_counts_row_gathers():
+    """With tracing on, one drive records nnz x (N - 1) factor-row gathers
+    under ``stream/row_gathers``; with tracing off it records nothing."""
+    from repro import obs
+    from repro.kernels.stream_mttkrp import fused_stream_mttkrp
+    csf, fs = _small_stream_case()
+    obs.disable()
+    obs.get_tracer().clear()
+    try:
+        fused_stream_mttkrp(csf, fs, lowering="xla")
+        assert "stream/row_gathers" not in obs.get_tracer().counters()
+        obs.enable()
+        fused_stream_mttkrp(csf, fs, lowering="xla")
+        counters = obs.get_tracer().counters()
+        assert counters["stream/row_gathers"] == csf.nnz * (len(fs) - 1)
+    finally:
+        obs.disable()
+        obs.get_tracer().clear()
+
+
 def test_fused_stream_unknown_lowering_raises():
     from repro.kernels.stream_mttkrp import fused_stream_mttkrp
     csf, fs = _small_stream_case(nnz=50)

@@ -19,6 +19,7 @@ from jax.sharding import SingleDeviceSharding
 
 NELL2_DIMS = (12092, 9184, 28818)
 NELL2_NNZ_CUT = 76_879_419 // 8
+NELL2_NNZ_QUARTER = 19_196_820   # the nell2.cpals cell's, duplicates merged
 RANK = 32
 ROWS = 256                       # PsramConfig().rows: nonzeros per block
 
@@ -96,19 +97,22 @@ def test_blocked_segment_sum_compiles(shape_of):
     assert "tpu_custom_call" in c.as_text()
 
 
-def _stream_args(shape_of, mode):
-    """Fused-stream operands for the 1/8-nell-2 stream rooted at ``mode``,
-    with the autotuner's default chunk of 8192 nonzeros."""
+def _stream_args(shape_of, mode, nnz=NELL2_NNZ_CUT, n_seg=ROWS):
+    """Fused-stream operands for a nell-2 stream of ``nnz`` nonzeros rooted
+    at ``mode`` (default 1/8 of nell-2), with the autotuner's default chunk
+    of 8192 nonzeros: each non-target factor packed as ``(J, R + 4)`` int8
+    rows, codes and scale (``stream_mttkrp.pack_rows``)."""
+    from repro.kernels.stream_mttkrp import SCALE_LANES
+
     e = 8192 // ROWS
-    nb = -(-NELL2_NNZ_CUT // (e * ROWS))
-    qs = tuple(shape_of((1, 1) if d == mode else (n, RANK), jnp.int8)
-               for d, n in enumerate(NELL2_DIMS))
-    ss = tuple(shape_of((1, 1) if d == mode else (n, 1), jnp.float32)
+    nb = -(-nnz // (e * ROWS))
+    ps = tuple(shape_of((1, 1) if d == mode else (n, RANK + SCALE_LANES),
+                        jnp.int8)
                for d, n in enumerate(NELL2_DIMS))
     return (shape_of((nb, e, ROWS, 3), jnp.int32),
             shape_of((nb, e, ROWS), jnp.float32),
             shape_of((nb, e, ROWS), jnp.int32),
-            shape_of((nb, e * ROWS), jnp.int32), qs, ss)
+            shape_of((nb, e * n_seg), jnp.int32), ps)
 
 
 def test_stream_mttkrp_tpu_lowering_compiles(shape_of, monkeypatch):
@@ -128,6 +132,28 @@ def test_stream_mttkrp_tpu_lowering_compiles(shape_of, monkeypatch):
     # the (28818, 32) f32 result, padded only to the chip's tiling
     out = c.memory_analysis().output_size_in_bytes
     assert NELL2_DIMS[mode] * RANK * 4 <= out < 1.01 * NELL2_DIMS[mode] * RANK * 4
+
+
+def test_stream_mttkrp_one_int8_gather_per_factor(shape_of):
+    """The ``"xla"`` lowering at the ``nell2.cpals`` cell's shapes (1/4 of
+    nell-2, 19,196,820 nonzeros, mode 0, ``n_seg`` 4) gathers each
+    non-target factor once per nonzero: exactly N - 1 = 2 gathers in the
+    compiled program, each of a packed ``(R + 4)``-byte int8 row (codes
+    and scale), and no f32 scale gather beside them."""
+    import re
+
+    from repro.kernels.stream_mttkrp import SCALE_LANES, _LOWERING_FNS
+
+    mode, n_seg = 0, 4
+    fn = _LOWERING_FNS["xla"]
+    c = _compile(lambda *a: fn(*a, mode, n_seg, 16, NELL2_DIMS[mode]),
+                 *_stream_args(shape_of, mode, nnz=NELL2_NNZ_QUARTER,
+                               n_seg=n_seg))
+    gathers = re.findall(r"= (\w+)\[([\d,]*)\]\S* gather\(", c.as_text())
+    assert len(gathers) == len(NELL2_DIMS) - 1, gathers
+    for dtype, dims in gathers:
+        assert dtype == "s8"
+        assert int(dims.split(",")[-1]) == RANK + SCALE_LANES
 
 
 def test_stream_mttkrp_pallas_lowering_refused(shape_of):
